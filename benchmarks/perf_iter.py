@@ -12,6 +12,7 @@ import os
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake host devices, never the chip
 
 import argparse
 import dataclasses

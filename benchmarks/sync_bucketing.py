@@ -23,6 +23,7 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags +
                                " --xla_force_host_platform_device_count=4")
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake host devices, never the chip
 
 import argparse
 import json

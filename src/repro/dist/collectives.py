@@ -12,10 +12,8 @@ calls inside that region:
     pmeans in full.
   * ``dp_sync_grads`` — the one-call entry point: compress -> pmean ->
     decompress with error feedback under a CompressionPlan.
-  * ``shard_map_dp`` — version shim: newer jax exposes ``jax.shard_map``
-    with ``axis_names=``/``check_vma=``; older releases have
-    ``jax.experimental.shard_map.shard_map`` with the complementary
-    ``auto=``/``check_rep=`` spelling. The step builder targets one surface.
+  * ``shard_map_dp`` — ``jax.shard_map`` with the DP axes manual and the
+    rest (``model``) left to GSPMD.
 """
 from __future__ import annotations
 
@@ -87,23 +85,8 @@ def shard_map_dp(f, mesh, in_specs, out_specs, manual_axes,
 
     The 'model' axis stays AUTO so GSPMD applies the TP rules from
     dist/sharding.py inside the body, while the (pod, data) gradient sync
-    is explicit (EDGC's compressed pmeans). Bridges the two shard_map
-    APIs: ``jax.shard_map(axis_names=..., check_vma=...)`` on current jax,
-    ``jax.experimental.shard_map.shard_map(auto=..., check_rep=...)`` on
-    older releases.
+    is explicit (EDGC's compressed pmeans).
     """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    # Legacy partial-auto manual subgroups crash XLA's partitioner
-    # ("Check failed: sharding.IsManualSubgroup()") whenever an auto axis
-    # has size > 1, so bind EVERY axis manual instead. The in/out specs
-    # never mention the non-DP axes, so those ranks carry replicated
-    # compute — same math, no TP compute split — and GSPMD reshards at the
-    # jit boundary. Current jax takes the partial-auto branch above and
-    # keeps real tensor parallelism inside the body.
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check, auto=frozenset())
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual_axes),
+                         check_vma=check)

@@ -12,8 +12,9 @@ Tiling: (bm, bn) VMEM tiles of the gradient, MXU-aligned (multiples of 128
 on the contracting dims); the thin factor (n x r or m x r panel, r <= 256)
 stays resident across the accumulation grid axis. fp32 accumulation.
 
-All kernels run under ``interpret=True`` on CPU (how tests validate them
-against ref.py) and compile for TPU with the same BlockSpecs.
+Every entry point takes ``interpret`` with no default: ``True`` on CPU (how
+tests validate them against ref.py), ``False`` to compile for the TPU with
+the same BlockSpecs. ops.py picks it from the backend.
 """
 from __future__ import annotations
 
@@ -45,7 +46,7 @@ def _p_kernel(g_ref, e_ref, q_ref, o_ref):
 
 
 def ef_lowrank_p(grad, err, q, *, bm: int = 256, bn: int = 512,
-                 interpret: bool = True):
+                 interpret: bool):
     """P = (grad + err) @ q.  grad/err (m, n), q (n, r) -> (m, r) fp32."""
     m, n = grad.shape
     r = q.shape[1]
@@ -76,7 +77,7 @@ def _q_kernel(g_ref, e_ref, p_ref, o_ref):
 
 
 def ef_lowrank_q(grad, err, p_hat, *, bm: int = 512, bn: int = 256,
-                 interpret: bool = True):
+                 interpret: bool):
     """Q = (grad + err)^T @ p_hat.  grad/err (m, n), p_hat (m, r) -> (n, r)."""
     m, n = grad.shape
     r = p_hat.shape[1]
@@ -106,7 +107,7 @@ def _dec_kernel(p_ref, q_ref, g_ref, e_ref, ghat_ref, newerr_ref):
 
 
 def decompress_residual(p_hat, q, grad, err, *, bm: int = 256, bn: int = 512,
-                        interpret: bool = True):
+                        interpret: bool):
     """(g_hat, new_err) both (m, n), one pass, no accumulation grid axis."""
     m, n = grad.shape
     r = q.shape[1]
@@ -135,24 +136,40 @@ def decompress_residual(p_hat, q, grad, err, *, bm: int = 256, bn: int = 512,
 
 # ------------------------------------------------------- Gram-Schmidt panel
 def _gs_kernel(p_ref, o_ref, *, r: int, eps: float):
-    """Single-block modified Gram-Schmidt; the (m, r) panel lives in VMEM.
+    """Single-block Gram-Schmidt; the (m, r) panel lives in VMEM.
 
-    r is static and small (<= 256): the column loop unrolls; each step is a
-    VPU dot + rank-1 update on the resident panel.
+    r is static and small (<= 256); each column step is a few masked VPU
+    passes over the resident panel.
     """
-    p = p_ref[...].astype(F32)
-    for i in range(r):
-        v = p[:, i]
-        if i > 0:
-            u = p[:, :i]                          # already orthonormal
-            coef = jnp.einsum("mk,m->k", u, v)    # (i,)
-            v = v - u @ coef
+    o_ref[...] = _gram_schmidt(p_ref[...].astype(F32), r, eps)
+
+
+def _gram_schmidt(p, r: int, eps: float):
+    """Gram-Schmidt on an (m, r) value, column by column, on the whole panel.
+
+    Mosaic lowers neither a scatter (``p.at[:, i].set``) nor a matrix-vector
+    product against an unaligned column slice, so every step is a masked
+    full-panel VPU pass: extract column i, project out the columns before
+    it (twice: one classical pass loses orthogonality as cond(p)^2 * eps),
+    normalize, and write it back with a column-mask select. The column
+    loop is a ``fori_loop``; unrolled, its compile time grows with m * r^2.
+    """
+    col = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
+
+    def column(i, p):
+        at_i = col == i
+        v = jnp.sum(jnp.where(at_i, p, 0.0), axis=1, keepdims=True)  # (m, 1)
+        for _ in range(2):   # projecting twice keeps ill-conditioned panels
+            coef = jnp.sum(jnp.where(col < i, p, 0.0) * v, axis=0,
+                           keepdims=True)                # (1, r), zero >= i
+            v = v - jnp.sum(p * coef, axis=1, keepdims=True)
         v = v / (jnp.sqrt(jnp.sum(v * v)) + eps)
-        p = p.at[:, i].set(v)
-    o_ref[...] = p
+        return jnp.where(at_i, v, p)
+
+    return jax.lax.fori_loop(0, r, column, p)
 
 
-def gram_schmidt_panel(p, *, eps: float = 1e-8, interpret: bool = True):
+def gram_schmidt_panel(p, *, eps: float = 1e-8, interpret: bool):
     """Orthonormalize an (m, r) panel in one VMEM-resident kernel call.
 
     VMEM budget: m * r * 4 bytes (<= ~4 MB for m=16384, r=64). ops.py falls
@@ -186,7 +203,7 @@ def _p3_kernel(g_ref, e_ref, q_ref, o_ref):
 
 
 def ef_lowrank_p_batched(grad, err, q, *, bm: int = 256, bn: int = 512,
-                         interpret: bool = True):
+                         interpret: bool):
     """P[e] = (grad[e] + err[e]) @ q[e].  (E, m, n) x (E, n, r) -> (E, m, r)."""
     num_e, m, n = grad.shape
     r = q.shape[-1]
@@ -216,7 +233,7 @@ def _q3_kernel(g_ref, e_ref, p_ref, o_ref):
 
 
 def ef_lowrank_q_batched(grad, err, p_hat, *, bm: int = 512, bn: int = 256,
-                         interpret: bool = True):
+                         interpret: bool):
     """Q[e] = (grad[e] + err[e])^T @ p_hat[e].  -> (E, n, r)."""
     num_e, m, n = grad.shape
     r = p_hat.shape[-1]
@@ -245,7 +262,7 @@ def _dec3_kernel(p_ref, q_ref, g_ref, e_ref, ghat_ref, newerr_ref):
 
 
 def decompress_residual_batched(p_hat, q, grad, err, *, bm: int = 256,
-                                bn: int = 512, interpret: bool = True):
+                                bn: int = 512, interpret: bool):
     """(g_hat, new_err) both (E, m, n); one pass, no accumulation axis."""
     num_e, m, n = grad.shape
     r = q.shape[-1]
@@ -273,20 +290,11 @@ def decompress_residual_batched(p_hat, q, grad, err, *, bm: int = 256,
 
 
 def _gs3_kernel(p_ref, o_ref, *, r: int, eps: float):
-    p = p_ref[0].astype(F32)
-    for i in range(r):
-        v = p[:, i]
-        if i > 0:
-            u = p[:, :i]
-            coef = jnp.einsum("mk,m->k", u, v)
-            v = v - u @ coef
-        v = v / (jnp.sqrt(jnp.sum(v * v)) + eps)
-        p = p.at[:, i].set(v)
-    o_ref[0] = p
+    o_ref[0] = _gram_schmidt(p_ref[0].astype(F32), r, eps)
 
 
 def gram_schmidt_panel_batched(p, *, eps: float = 1e-8,
-                               interpret: bool = True):
+                               interpret: bool):
     """Per-slice Gram-Schmidt over an (E, m, r) stack; grid over E, one
     VMEM-resident (m, r) panel per program (same budget as the 2-D panel)."""
     num_e, m, r = p.shape

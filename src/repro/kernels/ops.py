@@ -4,6 +4,11 @@ Callers use these; the wrappers pick interpret mode off the backend (CPU ->
 interpret=True so the identical kernel bodies execute in Python), route
 shapes the kernels can't tile (non-divisible, too large for a VMEM panel)
 to the ref.py oracles, and handle dtype promotion.
+
+The train step calls them inside a ``shard_map`` that leaves the ``model``
+axis to GSPMD, and XLA cannot partition a Mosaic call. ``_run``
+makes the axes still Auto at the call site manual for the kernel alone,
+with every operand replicated.
 """
 from __future__ import annotations
 
@@ -11,6 +16,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from . import entropy_hist as _hist
 from . import lowrank as _lr
@@ -24,6 +30,22 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def _run(kernel, *args, **static):
+    """Call a Pallas kernel: interpreted on CPU, compiled elsewhere.
+
+    Inside a region with Auto mesh axes the kernel runs whole on each
+    device of those axes, its operands replicated.
+    """
+    fn = partial(kernel, interpret=_interpret(), **static)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
+    if auto:
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                           axis_names=auto, check_vma=False)
+    return fn(*args)
+
+
 def _tileable(m: int, n: int) -> bool:
     return m % 128 == 0 and n % 128 == 0
 
@@ -33,7 +55,7 @@ def lowrank_p(grad, err, q):
     m, n = grad.shape
     if not _tileable(m, n):
         return ref.ef_lowrank_p(grad, err, q)
-    return _lr.ef_lowrank_p(grad, err, q, interpret=_interpret())
+    return _run(_lr.ef_lowrank_p, grad, err, q)
 
 
 @partial(jax.jit, static_argnames=())
@@ -41,7 +63,7 @@ def lowrank_q(grad, err, p_hat):
     m, n = grad.shape
     if not _tileable(m, n):
         return ref.ef_lowrank_q(grad, err, p_hat)
-    return _lr.ef_lowrank_q(grad, err, p_hat, interpret=_interpret())
+    return _run(_lr.ef_lowrank_q, grad, err, p_hat)
 
 
 @partial(jax.jit, static_argnames=())
@@ -49,7 +71,7 @@ def decompress_residual(p_hat, q, grad, err):
     m, n = grad.shape
     if not _tileable(m, n):
         return ref.decompress_residual(p_hat, q, grad, err)
-    return _lr.decompress_residual(p_hat, q, grad, err, interpret=_interpret())
+    return _run(_lr.decompress_residual, p_hat, q, grad, err)
 
 
 @partial(jax.jit, static_argnames=())
@@ -58,7 +80,7 @@ def orthonormalize(p):
     m, r = p.shape
     if m * r * 4 > (4 << 20) or m % 8 != 0:
         return jnp.linalg.qr(p.astype(F32))[0]
-    return _lr.gram_schmidt_panel(p, interpret=_interpret())
+    return _run(_lr.gram_schmidt_panel, p)
 
 
 # ------------------------------------------------ batched (E, m, n) stacks
@@ -71,7 +93,7 @@ def lowrank_p3(grad, err, q):
     _, m, n = grad.shape
     if not _tileable(m, n):
         return jax.vmap(ref.ef_lowrank_p)(grad, err, q)
-    return _lr.ef_lowrank_p_batched(grad, err, q, interpret=_interpret())
+    return _run(_lr.ef_lowrank_p_batched, grad, err, q)
 
 
 @partial(jax.jit, static_argnames=())
@@ -79,7 +101,7 @@ def lowrank_q3(grad, err, p_hat):
     _, m, n = grad.shape
     if not _tileable(m, n):
         return jax.vmap(ref.ef_lowrank_q)(grad, err, p_hat)
-    return _lr.ef_lowrank_q_batched(grad, err, p_hat, interpret=_interpret())
+    return _run(_lr.ef_lowrank_q_batched, grad, err, p_hat)
 
 
 @partial(jax.jit, static_argnames=())
@@ -87,8 +109,7 @@ def decompress_residual3(p_hat, q, grad, err):
     _, m, n = grad.shape
     if not _tileable(m, n):
         return jax.vmap(ref.decompress_residual)(p_hat, q, grad, err)
-    return _lr.decompress_residual_batched(p_hat, q, grad, err,
-                                           interpret=_interpret())
+    return _run(_lr.decompress_residual_batched, p_hat, q, grad, err)
 
 
 @partial(jax.jit, static_argnames=())
@@ -97,7 +118,7 @@ def orthonormalize3(p):
     _, m, r = p.shape
     if m * r * 4 > (4 << 20) or m % 8 != 0:
         return jax.vmap(lambda x: jnp.linalg.qr(x.astype(F32))[0])(p)
-    return _lr.gram_schmidt_panel_batched(p, interpret=_interpret())
+    return _run(_lr.gram_schmidt_panel_batched, p)
 
 
 # legacy alias used by core.powersgd's use_kernels path
@@ -107,7 +128,7 @@ def lowrank_matmul(m_mat, q):
     mm, nn = m_mat.shape
     if not _tileable(mm, nn):
         return m_mat.astype(F32) @ q.astype(F32)
-    return _lr.ef_lowrank_p(m_mat, zeros, q, interpret=_interpret())
+    return _run(_lr.ef_lowrank_p, m_mat, zeros, q)
 
 
 # ------------------------------------------------ wire-format bit packing
@@ -129,8 +150,7 @@ def pack_bits(codes, bits: int):
     nw_p = -(-nwords // _PACK_BW) * _PACK_BW
     c = jnp.pad(codes.astype(jnp.uint32), (0, nw_p * epw - n))
     slots = c.reshape(nw_p, epw).T            # row j = bit-slot j of each word
-    words = _pack.pack_words(slots, bits=bits, bw=_PACK_BW,
-                             interpret=_interpret())
+    words = _run(_pack.pack_words, slots, bits=bits, bw=_PACK_BW)
     return words[:nwords]
 
 
@@ -143,8 +163,7 @@ def unpack_bits(words, bits: int, n: int):
         return ref.unpack_bits(words, bits, n)
     nw_p = -(-nwords // _PACK_BW) * _PACK_BW
     w = jnp.pad(words, (0, nw_p - nwords))
-    slots = _pack.unpack_words(w, bits=bits, bw=_PACK_BW,
-                               interpret=_interpret())
+    slots = _run(_pack.unpack_words, w, bits=bits, bw=_PACK_BW)
     return slots.T.reshape(-1)[:n]
 
 
@@ -157,8 +176,7 @@ def sampled_entropy_hist(x, num_bins: int = 256, range_sigmas: float = 8.0):
     sigma = jnp.std(x) + eps
     lo = mu - range_sigmas * sigma
     width = (2.0 * range_sigmas * sigma) / num_bins
-    counts = _hist.hist_counts(x, lo, 1.0 / width, num_bins=num_bins,
-                               interpret=_interpret())
+    counts = _run(_hist.hist_counts, x, lo, 1.0 / width, num_bins=num_bins)
     p = counts / x.shape[0]
     plogp = jnp.where(p > 0, p * jnp.log(p + eps), 0.0)
     return -jnp.sum(plogp) + jnp.log(width + eps)

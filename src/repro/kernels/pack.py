@@ -40,7 +40,7 @@ def _unpack_kernel(w_ref, c_ref, *, bits: int):
 
 
 def pack_words(slots: jax.Array, *, bits: int, bw: int = 512,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """Pack slot-major codes (epw, nwords) -> uint32 words (nwords,).
 
     nwords must be a multiple of bw (the ops wrapper pads).
@@ -59,7 +59,7 @@ def pack_words(slots: jax.Array, *, bits: int, bw: int = 512,
 
 
 def unpack_words(words: jax.Array, *, bits: int, bw: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """Unpack uint32 words (nwords,) -> slot-major int32 codes (epw, nwords)."""
     epw = 32 // bits
     nwords = words.shape[0]

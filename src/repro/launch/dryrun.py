@@ -19,6 +19,7 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake host devices, never the chip
 
 import argparse
 import dataclasses
@@ -37,7 +38,7 @@ from repro.configs import ARCHS, INPUT_SHAPES, get_config, sharding_mode
 from repro.core import classify_leaves, make_plan
 from repro.core.compressor import NO_COMPRESSION
 from repro.dist.sharding import batch_pspec, cache_pspecs, param_shardings
-from repro.launch.mesh import dp_axes, make_production_mesh
+from repro.launch.mesh import dp_axes, make_pod_mesh, make_production_mesh
 from repro.models.model import ModelConfig, build_model
 from repro.optim import adam
 from repro.train.step import (
@@ -219,7 +220,7 @@ def _lower_outer_sync(cfg, mesh, params_shapes, rank):
 
     n_pods = mesh.devices.shape[list(mesh.axis_names).index("pod")]
     leads = mesh.devices.reshape(n_pods, -1)[:, 0]
-    omesh = jax.make_mesh((n_pods,), ("pod",), devices=list(leads))
+    omesh = make_pod_mesh(n_pods, devices=list(leads))
 
     leaves = classify_leaves(params_shapes, cfg.num_layers, 1, min_dim=128)
     plan = make_plan("fixed", leaves, fixed_rank=rank, num_stages=1)

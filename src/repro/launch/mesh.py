@@ -19,6 +19,7 @@ between pod and data so each pipeline stage owns a contiguous DP group.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -30,18 +31,30 @@ __all__ = [
 ]
 
 
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    JAX 0.9 makes mesh axes ``Explicit`` by default, which puts sharding in
+    the array types. The models and the partial-auto ``shard_map`` in
+    dist/collectives.py leave the ``model`` axis to GSPMD's propagation, so
+    every mesh here is built Auto.
+    """
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
+
+
 def make_production_mesh(*, multi_pod: bool = False, pipe: int = 0):
     if pipe and pipe > 1:
         data = (16 * 16) // (pipe * 16)
         if data < 1 or (pipe * data * 16) != 256:
             raise ValueError(f"pipe={pipe} does not divide the 256-chip pod")
         if multi_pod:
-            return jax.make_mesh((2, pipe, data, 16),
-                                 ("pod", "pipe", "data", "model"))
-        return jax.make_mesh((pipe, data, 16), ("pipe", "data", "model"))
+            return _mesh((2, pipe, data, 16), ("pod", "pipe", "data", "model"))
+        return _mesh((pipe, data, 16), ("pipe", "data", "model"))
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0, pipe: int = 0,
@@ -51,17 +64,14 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0, pipe: int = 0,
     ``devices``: explicit device subset (elastic training builds one
     pod-local mesh per pod over disjoint subsets; ``None`` = all devices).
     """
-    kw = {} if devices is None else {"devices": devices}
     if pipe:
         if pod:
-            return jax.make_mesh((pod, pipe, data, model),
-                                 ("pod", "pipe", "data", "model"), **kw)
-        return jax.make_mesh((pipe, data, model),
-                             ("pipe", "data", "model"), **kw)
+            return _mesh((pod, pipe, data, model),
+                         ("pod", "pipe", "data", "model"), devices)
+        return _mesh((pipe, data, model), ("pipe", "data", "model"), devices)
     if pod:
-        return jax.make_mesh((pod, data, model),
-                             ("pod", "data", "model"), **kw)
-    return jax.make_mesh((data, model), ("data", "model"), **kw)
+        return _mesh((pod, data, model), ("pod", "data", "model"), devices)
+    return _mesh((data, model), ("data", "model"), devices)
 
 
 def make_pod_mesh(n_pods: int, devices=None):
@@ -71,8 +81,7 @@ def make_pod_mesh(n_pods: int, devices=None):
     crosses it. This is the axis the DiLoCo outer optimizer all-reduces
     the EDGC-compressed outer deltas over.
     """
-    kw = {} if devices is None else {"devices": devices}
-    return jax.make_mesh((n_pods,), ("pod",), **kw)
+    return _mesh((n_pods,), ("pod",), devices)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -143,6 +143,8 @@ def main() -> None:
     ap.add_argument("--out", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     from repro.train.faults import RecoveryConfig, parse_inject
     faults = parse_inject(args.inject) if args.inject else None

@@ -542,8 +542,12 @@ def make_pipeline_train_step(model: Model, mesh, cfg):
     manual = ("pipe",) + tuple(axes_dp)
     # Stashed policies bound the backward's residual span by the segment
     # width, so per-unit remat inside the stage would only re-add the
-    # recompute the stash exists to remove — replay keeps cfg.remat.
-    part = make_partition(model, S, remat=cfg.remat and stash == "replay")
+    # recompute the stash exists to remove. Replay keeps per-unit remat
+    # when the step or, as on the flat path, the model config asks for it:
+    # without it a stage's VJP holds every unit's residuals at once.
+    part = make_partition(
+        model, S,
+        remat=(cfg.remat or model.config.remat) and stash == "replay")
     segs = stash_segments(stash, part.num_units(),
                           getattr(cfg, "stash_every", 2))
     n_stash = len(segs) - 1

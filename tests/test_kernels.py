@@ -80,6 +80,19 @@ def test_gram_schmidt_panel_sweep(m, r):
     np.testing.assert_allclose(overlap, np.eye(r), atol=2e-3)
 
 
+@pytest.mark.parametrize("cond", [1e2, 1e4, 1e6])
+def test_gram_schmidt_panel_ill_conditioned(cond):
+    """PowerSGD's warm-started P = M Q can be far from orthogonal; one
+    classical Gram-Schmidt pass then leaves it far from orthonormal."""
+    m, r = 1024, 32
+    rng = np.random.default_rng(40)
+    u = np.linalg.qr(rng.standard_normal((m, r)))[0]
+    v = np.linalg.qr(rng.standard_normal((r, r)))[0]
+    p = jnp.asarray((u * np.logspace(0, -np.log10(cond), r)) @ v, jnp.float32)
+    got = np.asarray(lr.gram_schmidt_panel(p, interpret=True))
+    np.testing.assert_allclose(got.T @ got, np.eye(r), atol=1e-2)
+
+
 @pytest.mark.parametrize("n", [1000, 4096, 100_000])
 @pytest.mark.parametrize("bins", [64, 256])
 def test_entropy_hist_kernel_sweep(n, bins):
