@@ -4,12 +4,10 @@
 what a run did — entropy and DAC-rank trajectories, wire bytes saved vs the
 uncompressed baseline, pipeline bubble fraction, measured step time, and the
 fault/recovery timeline — all from the structured records the trainer's
-``MetricsRegistry`` emitted. No JAX import is needed to read a report;
-``--trace`` (re-emit a Chrome trace from the run's schedule shape and
-measured step time) is the only path that touches the schedule simulator.
+``MetricsRegistry`` emitted. No JAX import is needed to read a report.
 
     python -m repro.launch.report runs/obs_run
-    python -m repro.launch.report runs/obs_run --trace trace.json --csv m.csv
+    python -m repro.launch.report runs/obs_run --csv m.csv
 """
 from __future__ import annotations
 
@@ -159,41 +157,11 @@ def build_report(records: list[dict]) -> list[str]:
     return lines
 
 
-def _emit_trace(records: list[dict], path: str) -> None:
-    meta = next((e for e in _events(records, "run_meta")), None)
-    if meta is None or not meta.get("data", {}).get("pipelined"):
-        raise SystemExit("--trace needs a run_meta event from a pipelined run")
-    d = meta["data"]
-    S, M = int(d["num_stages"]), int(d["num_microbatches"])
-    schedule = d.get("schedule", "1f1b")
-    from repro.obs.trace import (tick_trace_events, validate_trace,
-                                 write_chrome_trace)
-    from repro.pipeline.schedule import simulate_schedule
-    walls = _scalars(records, "wall_s")
-    sim = simulate_schedule(schedule, S, M)
-    if len(walls) >= 2:
-        dt = (walls[-1][1] - walls[0][1]) / max(1, walls[-1][0] - walls[0][0])
-        scale = dt / float(sim["makespan"])
-    else:
-        scale = 1e-3
-    events = tick_trace_events(schedule, S, M, t_f=scale, t_b=scale,
-                               time_unit_us=1e6)
-    write_chrome_trace(path, events,
-                       metadata={"source": "report", "schedule": schedule,
-                                 "num_stages": S, "num_microbatches": M})
-    stats = validate_trace({"traceEvents": events})
-    print(f"trace: {path} ({stats['spans']} spans, "
-          f"{stats['tracks']} tracks)")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(
         description="summarize a telemetry JSONL run record")
     ap.add_argument("run", help="run directory (containing metrics.jsonl) "
                                 "or a .jsonl path")
-    ap.add_argument("--trace", default=None,
-                    help="re-emit a Chrome trace JSON from the run's "
-                         "schedule shape and measured step time")
     ap.add_argument("--csv", default=None,
                     help="export scalar/series/counter records as CSV")
     args = ap.parse_args()
@@ -206,8 +174,6 @@ def main() -> None:
     if args.csv:
         write_csv(records, args.csv)
         print(f"csv: {args.csv}")
-    if args.trace:
-        _emit_trace(records, args.trace)
 
 
 if __name__ == "__main__":

@@ -132,14 +132,11 @@ def main() -> None:
                     help="write structured telemetry (scalars/series/events) "
                          "as JSONL to <dir>/metrics.jsonl; read it back with "
                          "python -m repro.launch.report <dir>")
-    ap.add_argument("--trace", default=None,
-                    help="emit a Chrome trace-event JSON of the pipeline "
-                         "schedule (Perfetto-loadable) to this path, with "
-                         "tick durations scaled to the measured mean step "
-                         "time (pipelined runs only)")
     ap.add_argument("--profile", default=None, metavar="LOGDIR",
                     help="wrap the run in a jax.profiler trace written to "
-                         "LOGDIR (view with TensorBoard/Perfetto)")
+                         "LOGDIR (view with TensorBoard/Perfetto): device "
+                         "ops carry the step's edgc.* scopes, the host "
+                         "line Trainer.run's edgc.* spans")
     ap.add_argument("--out", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
@@ -283,35 +280,6 @@ def main() -> None:
               f"{trainer.bytes_wire_raw} coded/raw payload bytes "
               f"({trainer.bytes_synced / trainer.bytes_wire_raw:.2%})")
 
-    if args.trace:
-        if not args.pipe:
-            raise SystemExit("--trace requires --pipe: the tick tracer "
-                             "renders the pipeline schedule")
-        from repro.obs import (load_trace, tick_trace_events, validate_trace,
-                               write_chrome_trace)
-        from repro.pipeline.schedule import simulate_schedule
-        S, M = args.pipe, (args.micro or args.pipe)
-        sim = simulate_schedule(args.schedule, S, M)
-        # Scale the unit-tick spans so the trace's makespan matches the
-        # measured mean step wall time (first->last history record).
-        if len(hist) >= 2 and hist[-1]["step"] > hist[0]["step"]:
-            mean_step_s = ((hist[-1]["wall_s"] - hist[0]["wall_s"])
-                           / (hist[-1]["step"] - hist[0]["step"]))
-        else:
-            mean_step_s = float(sim["makespan"])
-        scale = mean_step_s / float(sim["makespan"])
-        events = tick_trace_events(
-            args.schedule, S, M, t_f=scale, t_b=scale,
-            sync_plan=trainer.overlap_plan, stash_policy=args.stash,
-            n_units=trainer._part.num_units(), stash_every=args.stash_every,
-            time_unit_us=1e6)
-        write_chrome_trace(args.trace, events, metadata={
-            "arch": cfg.name, "schedule": args.schedule, "S": S, "M": M,
-            "mean_step_s": mean_step_s})
-        summary = validate_trace(load_trace(args.trace))
-        print(f"trace: {args.trace} — {summary['spans']} spans on "
-              f"{summary['tracks']} stage tracks, "
-              f"{summary['end_us']/1e6:.3f}s span horizon")
     trainer.metrics.close()
     if trainer.recovery is not None:
         print(f"recovery: {trainer.recovery.as_dict()}")

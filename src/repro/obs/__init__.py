@@ -1,14 +1,14 @@
-"""Unified telemetry: structured metrics, tick tracing, run reports.
+"""Unified telemetry: structured metrics, profiler traces, run reports.
 
 - ``repro.obs.metrics`` — :class:`MetricsRegistry` with typed
   scalar/series/counter/event emitters and pluggable sinks (JSONL file,
   in-memory for tests, CSV export). Device values are host-fetched in one
   batched ``block_until_ready`` at flush boundaries only.
-- ``repro.obs.trace`` — pipeline tick tracer: tick tables + overlap plan
-  -> Chrome trace-event JSON (Perfetto), plus the ``--profile``
-  ``jax.profiler`` hook.
+- ``repro.obs.scopes`` — the names of the train step's device scopes
+  and of ``Trainer.run``'s host spans, as the profiler trace carries them.
+- ``repro.obs.trace`` — the ``--profile`` ``jax.profiler`` hook.
 - ``repro.launch.report`` — CLI rendering a run's JSONL telemetry as a
-  text summary and re-emitting the trace.
+  text summary.
 """
 from repro.obs.metrics import (  # noqa: F401
     JsonlSink,
@@ -17,14 +17,7 @@ from repro.obs.metrics import (  # noqa: F401
     read_jsonl,
     write_csv,
 )
-from repro.obs.trace import (  # noqa: F401
-    expected_span_count,
-    load_trace,
-    profiler_session,
-    tick_trace_events,
-    validate_trace,
-    write_chrome_trace,
-)
+from repro.obs.trace import profiler_session  # noqa: F401
 
 __all__ = [
     "JsonlSink",
@@ -32,10 +25,5 @@ __all__ = [
     "MetricsRegistry",
     "read_jsonl",
     "write_csv",
-    "tick_trace_events",
-    "write_chrome_trace",
-    "load_trace",
-    "validate_trace",
-    "expected_span_count",
     "profiler_session",
 ]

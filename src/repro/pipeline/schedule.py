@@ -75,6 +75,7 @@ from repro.dist.collectives import make_dp_pmean, shard_map_dp
 from repro.dist.sharding import param_pspecs, stage_param_pspecs
 from repro.launch.mesh import dp_axes, pipe_size
 from repro.models.model import Model
+from repro.obs import scopes
 from repro.pipeline import sync as psync
 from repro.pipeline.partition import make_partition
 
@@ -426,11 +427,9 @@ def tick_spans(name: str, S: int, M: int,
         {"stage": s, "tick": t, "kind": "F"|"B", "mb": j,
          "start": seconds, "end": seconds}
 
-    This is the timing engine ``simulate_schedule`` aggregates over and
-    the obs tick tracer (``repro.obs.trace``) renders as Chrome
-    trace-event spans: each F(s, j) waits for F(s-1, j) and the rank's
-    previous op; each B(s, j) waits for B(s+1, j) (or its own F on the
-    last stage).
+    This is the timing engine ``simulate_schedule`` aggregates over:
+    each F(s, j) waits for F(s-1, j) and the rank's previous op; each
+    B(s, j) waits for B(s+1, j) (or its own F on the last stage).
     """
     table = slot_table(name, S, M)
     end_f: dict[tuple[int, int], float] = {}
@@ -706,15 +705,17 @@ def make_pipeline_train_step(model: Model, mesh, cfg):
 
                 return run
 
-            return lax.switch(s_idx, [mk(s) for s in range(S)], carry)
+            with jax.named_scope(scopes.COMPRESS):
+                return lax.switch(s_idx, [mk(s) for s in range(S)], carry)
 
         for t in range(n_ticks):
             if t < M + S - 1:
                 off = t - s_idx
                 valid_f = (off >= 0) & (off < M)
                 jf = jnp.clip(off, 0, M - 1)
-                y, loss_mb, interior = rank_fwd(stage_p, shared_p,
-                                                take_mb(jf), fwd_recv)
+                with jax.named_scope(scopes.FORWARD):
+                    y, loss_mb, interior = rank_fwd(stage_p, shared_p,
+                                                    take_mb(jf), fwd_recv)
                 loss_acc = loss_acc + jnp.where(valid_f, loss_mb, 0.0)
                 upd = lambda r, v: jnp.where(
                     valid_f,
@@ -750,17 +751,18 @@ def make_pipeline_train_step(model: Model, mesh, cfg):
                                         jnp.zeros_like(a)), bwd_recv)
                 ct_loss = jnp.where(valid_b, inv_M, 0.0)
                 add32 = lambda a, g: a + g.astype(jnp.float32)
-                for i in range(len(segs) - 1, -1, -1):
-                    xin = (x_saved if i == 0 else
-                           tmap(lambda a, i=i: a[i - 1], stash_saved))
+                with jax.named_scope(scopes.BACKWARD):
+                    for i in range(len(segs) - 1, -1, -1):
+                        xin = (x_saved if i == 0 else
+                               tmap(lambda a, i=i: a[i - 1], stash_saved))
 
-                    def seg(sp, sh, xr, mbj=mbj, i=i):
-                        return seg_fwd(sp, sh, xr, mbj, i)
+                        def seg(sp, sh, xr, mbj=mbj, i=i):
+                            return seg_fwd(sp, sh, xr, mbj, i)
 
-                    _, vjp = jax.vjp(seg, stage_p, shared_p, xin)
-                    gs, gsh, ct_carry = vjp((ct_carry, ct_loss))
-                    gacc_s = tmap(add32, gacc_s, gs)
-                    gacc_sh = tmap(add32, gacc_sh, gsh)
+                        _, vjp = jax.vjp(seg, stage_p, shared_p, xin)
+                        gs, gsh, ct_carry = vjp((ct_carry, ct_loss))
+                        gacc_s = tmap(add32, gacc_s, gs)
+                        gacc_sh = tmap(add32, gacc_sh, gsh)
                 bwd_recv = tmap(lambda a: lax.ppermute(a, "pipe", bwd_perm),
                                 ct_carry)
             if overlap and t in launch_at:
@@ -777,91 +779,94 @@ def make_pipeline_train_step(model: Model, mesh, cfg):
         gacc_sh = tmap(lambda g, p: psum_pipe(g).astype(p.dtype),
                        gacc_sh, shared_p)
 
-        if overlap:
-            # Residual chunks (whatever the drain window couldn't hide —
-            # all of stage 0's, whose slack is zero) run post-loop in the
-            # same per-stage switch; then the synced leaves reassemble in
-            # flatten order and the shared leaves finish exactly as the
-            # monolithic path does.
-            g_by_path = dict(zip(spaths, jax.tree_util.tree_leaves(gacc_s)))
+        with jax.named_scope(scopes.COMPRESS):
+            if overlap:
+                # Residual chunks (whatever the drain window couldn't hide —
+                # all of stage 0's, whose slack is zero) run post-loop in the
+                # same per-stage switch; then the synced leaves reassemble in
+                # flatten order and the shared leaves finish exactly as the
+                # monolithic path does.
+                g_by_path = dict(zip(spaths, jax.tree_util.tree_leaves(gacc_s)))
 
-            def fin(s):
-                ids = oplan.residual[s]
-                d = splans.d_of_stage[s]
-                need = sorted({p for ci in ids
-                               for p in chunks_by_d[d][ci].member_paths})
+                def fin(s):
+                    ids = oplan.residual[s]
+                    d = splans.d_of_stage[s]
+                    need = sorted({p for ci in ids
+                                   for p in chunks_by_d[d][ci].member_paths})
 
-                def run(c, ids=ids, d=d, need=need):
-                    parts, comp_c = c
-                    if ids:
-                        gb = {p: g_by_path[p] for p in need}
-                        upd, comp_c = sync_exec.run_chunks(
-                            d, ids, gb, comp_c, pmean_dp)
-                        parts = {p: upd.get(p, parts[p]) for p in spaths}
-                    return parts, comp_c
+                    def run(c, ids=ids, d=d, need=need):
+                        parts, comp_c = c
+                        if ids:
+                            gb = {p: g_by_path[p] for p in need}
+                            upd, comp_c = sync_exec.run_chunks(
+                                d, ids, gb, comp_c, pmean_dp)
+                            parts = {p: upd.get(p, parts[p]) for p in spaths}
+                        return parts, comp_c
 
-                return run
+                    return run
 
-            parts_f, comp2 = lax.switch(
-                s_idx, [fin(s) for s in range(S)], sync_carry)
-            synced_s = jax.tree_util.tree_unflatten(
-                stage_def, [parts_f[p] for p in spaths])
-            synced_sh = sync_exec.sync_shared(gacc_sh, pmean_dp)
-        else:
-            synced_s, synced_sh, comp2 = sync_exec.sync(
-                gacc_s, comp, pmean_dp, shared_grads=gacc_sh,
-                my_stage=s_idx)
+                parts_f, comp2 = lax.switch(
+                    s_idx, [fin(s) for s in range(S)], sync_carry)
+                synced_s = jax.tree_util.tree_unflatten(
+                    stage_def, [parts_f[p] for p in spaths])
+                synced_sh = sync_exec.sync_shared(gacc_sh, pmean_dp)
+            else:
+                synced_s, synced_sh, comp2 = sync_exec.sync(
+                    gacc_s, comp, pmean_dp, shared_grads=gacc_sh,
+                    my_stage=s_idx)
 
         if cfg.measure_entropy:
-            from repro.core.entropy import entropy_from_moments, sample_moments
-            # Ragged stage plans zero-pad each rank's stacks to the widest
-            # stage; pooling the PADDED leaves would count the exact-zero
-            # pad slots in n and bias sigma (and the Lemma-2 entropy) low.
-            # Each top-level key of the stage tree is one adapter stack —
-            # its live-unit mask drops pad samples so the pipelined pooled
-            # moments match the flat step's exactly.
-            z = jnp.zeros((), jnp.float32)
-            n1 = a1 = a2 = z
-            for key in sorted(synced_s):
-                kn, k1, k2 = sample_moments(
-                    synced_s[key], cfg.gds,
-                    lead_mask=part.stage_flags(key, s_idx))
-                n1, a1, a2 = n1 + kn, a1 + k1, a2 + k2
-            n2, c1, c2 = sample_moments(synced_sh, cfg.gds)
-            w = jnp.where(is_first, 1.0, 0.0)  # count shared leaves once
-            # Each rank scatters its pooled moments into its stage's slot
-            # and the (S,)-vectors psum over pipe: the SAME three Lemma-2
-            # collectives as the scalar pooling (the ISR-gate invariant —
-            # the off variant lowers exactly 3 fewer psums), but the slots
-            # now also yield the per-stage entropy series for free. Slot
-            # sums recover the pooled moments exactly: every other rank
-            # contributes zeros to a slot.
-            scatter = lambda v: jnp.zeros((S,), jnp.float32).at[s_idx].set(v)
-            n_vec = psum_pipe(scatter(n1 + w * n2))
-            s1_vec = psum_pipe(scatter(a1 + w * c1))
-            s2_vec = psum_pipe(scatter(a2 + w * c2))
-            entropy = entropy_from_moments(n_vec.sum(), s1_vec.sum(),
-                                           s2_vec.sum())
-            stage_entropy = entropy_from_moments(n_vec, s1_vec, s2_vec)
+            with jax.named_scope(scopes.ENTROPY):
+                from repro.core.entropy import entropy_from_moments, sample_moments
+                # Ragged stage plans zero-pad each rank's stacks to the widest
+                # stage; pooling the PADDED leaves would count the exact-zero
+                # pad slots in n and bias sigma (and the Lemma-2 entropy) low.
+                # Each top-level key of the stage tree is one adapter stack —
+                # its live-unit mask drops pad samples so the pipelined pooled
+                # moments match the flat step's exactly.
+                z = jnp.zeros((), jnp.float32)
+                n1 = a1 = a2 = z
+                for key in sorted(synced_s):
+                    kn, k1, k2 = sample_moments(
+                        synced_s[key], cfg.gds,
+                        lead_mask=part.stage_flags(key, s_idx))
+                    n1, a1, a2 = n1 + kn, a1 + k1, a2 + k2
+                n2, c1, c2 = sample_moments(synced_sh, cfg.gds)
+                w = jnp.where(is_first, 1.0, 0.0)  # count shared leaves once
+                # Each rank scatters its pooled moments into its stage's slot
+                # and the (S,)-vectors psum over pipe: the SAME three Lemma-2
+                # collectives as the scalar pooling (the ISR-gate invariant —
+                # the off variant lowers exactly 3 fewer psums), but the slots
+                # now also yield the per-stage entropy series for free. Slot
+                # sums recover the pooled moments exactly: every other rank
+                # contributes zeros to a slot.
+                scatter = lambda v: jnp.zeros((S,), jnp.float32).at[s_idx].set(v)
+                n_vec = psum_pipe(scatter(n1 + w * n2))
+                s1_vec = psum_pipe(scatter(a1 + w * c1))
+                s2_vec = psum_pipe(scatter(a2 + w * c2))
+                entropy = entropy_from_moments(n_vec.sum(), s1_vec.sum(),
+                                               s2_vec.sum())
+                stage_entropy = entropy_from_moments(n_vec, s1_vec, s2_vec)
         else:
             entropy = jnp.zeros((), jnp.float32)
             stage_entropy = jnp.zeros((S,), jnp.float32)
 
-        sumsq = lambda t: sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
-                              for l in jax.tree_util.tree_leaves(t))
-        gnorm = jnp.sqrt(psum_pipe(sumsq(synced_s)) + sumsq(synced_sh))
+        with jax.named_scope(scopes.OPTIMIZER):
+            sumsq = lambda t: sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
+                                  for l in jax.tree_util.tree_leaves(t))
+            gnorm = jnp.sqrt(psum_pipe(sumsq(synced_s)) + sumsq(synced_sh))
 
-        params_local = {"stage": stage_p, "shared": shared_p}
-        grads_local = {"stage": synced_s, "shared": synced_sh}
-        ost = adam.AdamState(
-            step=state["opt_step"],
-            m={"stage": squeeze(state["opt_m"]["stage"]),
-               "shared": state["opt_m"]["shared"]},
-            v={"stage": squeeze(state["opt_v"]["stage"]),
-               "shared": state["opt_v"]["shared"]},
-        )
-        new_p, ost, opt_mets = adam.update(params_local, grads_local, ost,
-                                           adam_cfg, gnorm=gnorm)
+            params_local = {"stage": stage_p, "shared": shared_p}
+            grads_local = {"stage": synced_s, "shared": synced_sh}
+            ost = adam.AdamState(
+                step=state["opt_step"],
+                m={"stage": squeeze(state["opt_m"]["stage"]),
+                   "shared": state["opt_m"]["shared"]},
+                v={"stage": squeeze(state["opt_v"]["stage"]),
+                   "shared": state["opt_v"]["shared"]},
+            )
+            new_p, ost, opt_mets = adam.update(params_local, grads_local, ost,
+                                               adam_cfg, gnorm=gnorm)
 
         unsq = lambda t: tmap(lambda a: a[None], t)
         new_state = {
@@ -873,7 +878,8 @@ def make_pipeline_train_step(model: Model, mesh, cfg):
             "comp": tmap(lambda a: a[None, None], comp2),
         }
         from repro.core.powersgd import ef_norm_sq
-        ef_norm = jnp.sqrt(pmean_dp(psum_pipe(ef_norm_sq(comp2))))
+        with jax.named_scope(scopes.COMPRESS):
+            ef_norm = jnp.sqrt(pmean_dp(psum_pipe(ef_norm_sq(comp2))))
         metrics = {"loss": loss, "entropy": entropy,
                    "stage_entropy": stage_entropy, "ef_norm": ef_norm,
                    **opt_mets}

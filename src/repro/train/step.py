@@ -37,6 +37,7 @@ from repro.dist.collectives import make_dp_pmean, shard_map_dp
 from repro.dist.sharding import batch_pspec, param_shardings
 from repro.launch.mesh import dp_axes
 from repro.models.model import Model
+from repro.obs import scopes
 from repro.optim import adam
 from repro.pipeline.config import PIPELINE_FIELDS
 
@@ -153,8 +154,8 @@ def make_train_step(model: Model, mesh, cfg: TrainStepConfig):
         inject = batch.pop("_inject", None)
 
         def lf(p):
-            loss, mets = loss_fn(p, batch)
-            return loss, mets
+            with jax.named_scope(scopes.FORWARD):
+                return loss_fn(p, batch)
 
         (loss, mets), grads = jax.value_and_grad(lf, has_aux=True)(params)
         if inject is not None:
@@ -163,36 +164,42 @@ def make_train_step(model: Model, mesh, cfg: TrainStepConfig):
                 lambda g: jnp.where(bad, jnp.full_like(g, jnp.nan), g), grads)
         pmean = make_dp_pmean(axes) if manual else (lambda x: x)
         loss = pmean(loss)
-        synced, comp = sync_exec.sync(grads, comp_in, pmean)
-        entropy = (grads_entropy(synced, cfg.gds)
-                   if cfg.measure_entropy else jnp.zeros((), jnp.float32))
-        opt_state = adam.AdamState(state["opt_step"], state["opt_m"], state["opt_v"])
-        if cfg.guard_nonfinite:
-            # Recovery guard: a non-finite loss or synced-grad norm (NaN
-            # injection, corrupted compressor payload, divergence) must not
-            # reach the optimizer OR the compressor's warm-start/EF state.
-            # The whole update is computed and discarded leaf-wise — the
-            # host sees metrics['skipped'] == 1 and resets the EF state.
-            gnorm = adam.global_norm(synced)
-            ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-            new_params, new_opt, opt_mets = adam.update(
-                params, synced, opt_state, adam_cfg, gnorm=gnorm)
-            keep = lambda new, old: jax.tree_util.tree_map(
-                lambda a, b: jnp.where(ok, a, b), new, old)
-            params = keep(new_params, params)
-            opt_state = adam.AdamState(
-                step=keep(new_opt.step, opt_state.step),
-                m=keep(new_opt.m, opt_state.m),
-                v=keep(new_opt.v, opt_state.v))
-            comp = keep(comp, comp_in)
-            skipped = 1.0 - ok.astype(jnp.float32)
+        with jax.named_scope(scopes.COMPRESS):
+            synced, comp = sync_exec.sync(grads, comp_in, pmean)
+        if cfg.measure_entropy:
+            with jax.named_scope(scopes.ENTROPY):
+                entropy = grads_entropy(synced, cfg.gds)
         else:
-            params, opt_state, opt_mets = adam.update(
-                params, synced, opt_state, adam_cfg)
-            skipped = None
+            entropy = jnp.zeros((), jnp.float32)
+        opt_state = adam.AdamState(state["opt_step"], state["opt_m"], state["opt_v"])
+        with jax.named_scope(scopes.OPTIMIZER):
+            if cfg.guard_nonfinite:
+                # Recovery guard: a non-finite loss or synced-grad norm (NaN
+                # injection, corrupted compressor payload, divergence) must not
+                # reach the optimizer OR the compressor's warm-start/EF state.
+                # The whole update is computed and discarded leaf-wise — the
+                # host sees metrics['skipped'] == 1 and resets the EF state.
+                gnorm = adam.global_norm(synced)
+                ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+                new_params, new_opt, opt_mets = adam.update(
+                    params, synced, opt_state, adam_cfg, gnorm=gnorm)
+                keep = lambda new, old: jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(ok, a, b), new, old)
+                params = keep(new_params, params)
+                opt_state = adam.AdamState(
+                    step=keep(new_opt.step, opt_state.step),
+                    m=keep(new_opt.m, opt_state.m),
+                    v=keep(new_opt.v, opt_state.v))
+                comp = keep(comp, comp_in)
+                skipped = 1.0 - ok.astype(jnp.float32)
+            else:
+                params, opt_state, opt_mets = adam.update(
+                    params, synced, opt_state, adam_cfg)
+                skipped = None
         # EF-residual norm on the per-worker comp state BEFORE the replica
         # dim is restored — one scalar, fetched lazily by the obs flush.
-        ef_norm = jnp.sqrt(pmean(powersgd.ef_norm_sq(comp)))
+        with jax.named_scope(scopes.COMPRESS):
+            ef_norm = jnp.sqrt(pmean(powersgd.ef_norm_sq(comp)))
         if manual:
             comp = jax.tree_util.tree_map(lambda a: a[None], comp)
         new_state = {

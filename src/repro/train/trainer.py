@@ -35,6 +35,7 @@ from repro.core.bucketing import bucketing_supported, make_bucket_layout
 from repro.core.config import SYNC_FIELDS, SyncConfig, alias_property, \
     resolve_embedded
 from repro.models.model import Model
+from repro.obs import scopes
 from repro.optim import adam
 from repro.train import checkpoint as ckpt_mod
 from repro.train.step import (
@@ -494,153 +495,156 @@ class Trainer:
         pending: list[tuple] = []
         step_idx = start
         while step_idx < end:
-            batch = next(batches)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            fired_now = [(i, ev) for i, ev in enumerate(self.faults.events)
-                         if not ev.on_round and ev.at == step_idx
-                         and i not in self._fired_faults]
-            self._fired_faults.update(i for i, _ in fired_now)
-            for _, ev in fired_now:
-                self.metrics.event("fault_injected", step=step_idx,
-                                   kind=ev.kind, at=int(ev.at))
-                if ev.kind == "corrupt_payload":
-                    self._poison_comp_state()
-                elif ev.kind == "torn_ckpt":
-                    self._tear_next_ckpt = True
-            if inject_nan_faults:
-                # Constant batch structure (one compiled variant): the flag
-                # array is present on EVERY step once any nan_grad fault is
-                # scheduled, zero except at the scheduled steps.
-                flag = float(any(ev.kind == "nan_grad"
-                                 for _, ev in fired_now))
-                bsz = next(iter(batch.values())).shape[0]
-                batch["_inject"] = jnp.full((bsz,), flag, jnp.float32)
-            # ISR (alpha) gate: off-iterations dispatch the entropy-off
-            # step variant, so the skipped measurements never lower any
-            # device work (§IV-B's "fraction of iterations" sampling).
-            measure = tcfg.measure_entropy and ctrl.wants_entropy(step_idx)
-            step_fn = self._get_step(measure)
-            self.state, mets = step_fn(self.state, batch)
+            with jax.profiler.StepTraceAnnotation(scopes.STEP,
+                                                  step_num=step_idx):
+                batch = next(batches)
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                fired_now = [(i, ev) for i, ev in enumerate(self.faults.events)
+                             if not ev.on_round and ev.at == step_idx
+                             and i not in self._fired_faults]
+                self._fired_faults.update(i for i, _ in fired_now)
+                for _, ev in fired_now:
+                    self.metrics.event("fault_injected", step=step_idx,
+                                       kind=ev.kind, at=int(ev.at))
+                    if ev.kind == "corrupt_payload":
+                        self._poison_comp_state()
+                    elif ev.kind == "torn_ckpt":
+                        self._tear_next_ckpt = True
+                if inject_nan_faults:
+                    # Constant batch structure (one compiled variant): the flag
+                    # array is present on EVERY step once any nan_grad fault is
+                    # scheduled, zero except at the scheduled steps.
+                    flag = float(any(ev.kind == "nan_grad"
+                                     for _, ev in fired_now))
+                    bsz = next(iter(batch.values())).shape[0]
+                    batch["_inject"] = jnp.full((bsz,), flag, jnp.float32)
+                # ISR (alpha) gate: off-iterations dispatch the entropy-off
+                # step variant, so the skipped measurements never lower any
+                # device work (§IV-B's "fraction of iterations" sampling).
+                measure = tcfg.measure_entropy and ctrl.wants_entropy(step_idx)
+                step_fn = self._get_step(measure)
+                self.state, mets = step_fn(self.state, batch)
 
-            self.bytes_synced += comp_bytes
-            self.bytes_wire_raw += raw_bytes
-            self.bytes_full += full_bytes
+                self.bytes_synced += comp_bytes
+                self.bytes_wire_raw += raw_bytes
+                self.bytes_full += full_bytes
 
-            step_ok = True
-            if rs is not None:
-                loss = float(mets["loss"])
-                skipped = float(mets.get("skipped", 0.0)) > 0.5
-                if skipped:
-                    # The compiled guard already refused the update; the
-                    # compressor warm-start/EF may still hold the garbage
-                    # that caused it (corrupted payload), so reset it.
-                    rs.skipped_steps += 1
-                    rs.anomalies += 1
-                    self.metrics.event("guard_skip", step=step_idx,
-                                       loss=loss)
-                    self._reset_comp_state()
-                    rs.ef_resets += 1
-                    self.metrics.counter("ef_resets", step=step_idx)
-                    self.metrics.event("ef_reset", step=step_idx)
-                    step_ok = False
-                elif not np.isfinite(loss):
-                    rs.anomalies += 1
-                    step_ok = False
-                    rolled = self._maybe_rollback()
-                    if rolled is not None:
-                        self.metrics.event("rollback", step=step_idx,
-                                           restored_step=int(rolled))
-                        self._maybe_fallback(ctrl)
-                        comp_bytes, raw_bytes, full_bytes = self._price_plan()
-                        stage_b = self.stage_bytes()
-                        step_idx = rolled
-                        continue
-                else:
-                    armed = (self._ema_seen >= rcfg.spike_warmup
-                             and step_idx >= rs.backoff_until)
-                    if (armed and rs.loss_ema is not None and rcfg.rollback
-                            and loss > rcfg.spike_factor
-                            * max(rs.loss_ema, 1e-8)):
+                step_ok = True
+                if rs is not None:
+                    loss = float(mets["loss"])
+                    skipped = float(mets.get("skipped", 0.0)) > 0.5
+                    if skipped:
+                        # The compiled guard already refused the update; the
+                        # compressor warm-start/EF may still hold the garbage
+                        # that caused it (corrupted payload), so reset it.
+                        rs.skipped_steps += 1
                         rs.anomalies += 1
+                        self.metrics.event("guard_skip", step=step_idx,
+                                           loss=loss)
+                        self._reset_comp_state()
+                        rs.ef_resets += 1
+                        self.metrics.counter("ef_resets", step=step_idx)
+                        self.metrics.event("ef_reset", step=step_idx)
+                        step_ok = False
+                    elif not np.isfinite(loss):
+                        rs.anomalies += 1
+                        step_ok = False
                         rolled = self._maybe_rollback()
                         if rolled is not None:
                             self.metrics.event("rollback", step=step_idx,
-                                               restored_step=int(rolled),
-                                               spike_loss=loss)
+                                               restored_step=int(rolled))
                             self._maybe_fallback(ctrl)
-                            comp_bytes, raw_bytes, full_bytes = \
-                                self._price_plan()
+                            comp_bytes, raw_bytes, full_bytes = self._price_plan()
                             stage_b = self.stage_bytes()
                             step_idx = rolled
                             continue
-                    rs.loss_ema = (loss if rs.loss_ema is None else
-                                   rcfg.ema_decay * rs.loss_ema
-                                   + (1 - rcfg.ema_decay) * loss)
-                    self._ema_seen += 1
-                if self._maybe_fallback(ctrl):
-                    comp_bytes, raw_bytes, full_bytes = self._price_plan()
-                    stage_b = self.stage_bytes()
-                if step_ok and not self._last_step_ok:
-                    self.metrics.event("recovered", step=step_idx)
-                self._last_step_ok = step_ok
+                    else:
+                        armed = (self._ema_seen >= rcfg.spike_warmup
+                                 and step_idx >= rs.backoff_until)
+                        if (armed and rs.loss_ema is not None and rcfg.rollback
+                                and loss > rcfg.spike_factor
+                                * max(rs.loss_ema, 1e-8)):
+                            rs.anomalies += 1
+                            rolled = self._maybe_rollback()
+                            if rolled is not None:
+                                self.metrics.event("rollback", step=step_idx,
+                                                   restored_step=int(rolled),
+                                                   spike_loss=loss)
+                                self._maybe_fallback(ctrl)
+                                comp_bytes, raw_bytes, full_bytes = \
+                                    self._price_plan()
+                                stage_b = self.stage_bytes()
+                                step_idx = rolled
+                                continue
+                        rs.loss_ema = (loss if rs.loss_ema is None else
+                                       rcfg.ema_decay * rs.loss_ema
+                                       + (1 - rcfg.ema_decay) * loss)
+                        self._ema_seen += 1
+                    if self._maybe_fallback(ctrl):
+                        comp_bytes, raw_bytes, full_bytes = self._price_plan()
+                        stage_b = self.stage_bytes()
+                    if step_ok and not self._last_step_ok:
+                        self.metrics.event("recovered", step=step_idx)
+                    self._last_step_ok = step_ok
 
-            # Buffer this step's device metrics + host-side snapshots; the
-            # host reads (on_entropy, history, telemetry) happen in-order at
-            # the next flush boundary. Snapshots are taken NOW because the
-            # cumulative byte ledgers and rank plan advance under the buffer.
-            pending.append((
-                step_idx, measure and step_ok, mets,
-                self.bytes_synced, self.bytes_wire_raw, self.bytes_full,
-                stage_b,
-                ctrl.dac.current_ranks() if not ctrl.in_warmup else [],
-                rs.as_dict() if rs is not None else None,
-                time.time() - t0,
-            ))
+                # Buffer this step's device metrics + host-side snapshots; the
+                # host reads (on_entropy, history, telemetry) happen in-order at
+                # the next flush boundary. Snapshots are taken NOW because the
+                # cumulative byte ledgers and rank plan advance under the buffer.
+                pending.append((
+                    step_idx, measure and step_ok, mets,
+                    self.bytes_synced, self.bytes_wire_raw, self.bytes_full,
+                    stage_b,
+                    ctrl.dac.current_ranks() if not ctrl.in_warmup else [],
+                    rs.as_dict() if rs is not None else None,
+                    time.time() - t0,
+                ))
 
-            at_window = (step_idx + 1) % window == 0
-            logged = (step_idx % tcfg.log_every == 0
-                      or step_idx == tcfg.total_steps - 1)
-            at_ckpt = bool(tcfg.ckpt_every
-                           and (step_idx + 1) % tcfg.ckpt_every == 0)
-            if at_window or logged or at_ckpt:
-                # Window ends flush BEFORE on_window_end so every gated
-                # entropy reading in the window reaches the DAC; records
-                # therefore snapshot the plan the step actually ran under.
-                self._flush_pending(pending, t0)
+                at_window = (step_idx + 1) % window == 0
+                logged = (step_idx % tcfg.log_every == 0
+                          or step_idx == tcfg.total_steps - 1)
+                at_ckpt = bool(tcfg.ckpt_every
+                               and (step_idx + 1) % tcfg.ckpt_every == 0)
+                if at_window or logged or at_ckpt:
+                    # Window ends flush BEFORE on_window_end so every gated
+                    # entropy reading in the window reaches the DAC; records
+                    # therefore snapshot the plan the step actually ran under.
+                    self._flush_pending(pending, t0)
 
-            if at_window:
-                plan_changed = ctrl.on_window_end(step_idx)
-                if plan_changed:
-                    self._apply_plan_change()
-                    self.metrics.event(
-                        "plan_change", step=step_idx,
-                        ranks=ctrl.dac.current_ranks())
-                # entropy-mode wire coding re-picks its bit width here,
-                # on the same cadence as plan changes (one recompile max
-                # per window)
-                if self._refresh_codec():
-                    plan_changed = True
-                    self.metrics.event(
-                        "wire_codec", step=step_idx,
-                        bits=int(self._codec.bits),
-                        entropy=self._last_entropy)
-                if plan_changed:
-                    comp_bytes, raw_bytes, full_bytes = self._price_plan()
-                    stage_b = self.stage_bytes()
+                if at_window:
+                    with jax.profiler.TraceAnnotation(scopes.WINDOW_END):
+                        plan_changed = ctrl.on_window_end(step_idx)
+                        if plan_changed:
+                            self._apply_plan_change()
+                            self.metrics.event(
+                                "plan_change", step=step_idx,
+                                ranks=ctrl.dac.current_ranks())
+                        # entropy-mode wire coding re-picks its bit width here,
+                        # on the same cadence as plan changes (one recompile max
+                        # per window)
+                        if self._refresh_codec():
+                            plan_changed = True
+                            self.metrics.event(
+                                "wire_codec", step=step_idx,
+                                bits=int(self._codec.bits),
+                                entropy=self._last_entropy)
+                        if plan_changed:
+                            comp_bytes, raw_bytes, full_bytes = self._price_plan()
+                            stage_b = self.stage_bytes()
 
-            if at_ckpt:
-                path = f"{tcfg.ckpt_path}_{step_idx+1}"
-                self.save_checkpoint(path, step=step_idx + 1)
-                self.metrics.event("checkpoint", step=step_idx, path=path)
-                if self._tear_next_ckpt:
-                    # torn_ckpt fault: simulate a crash mid-write AFTER the
-                    # save completed — the atomic-rename path cannot tear,
-                    # so the injector truncates the archive in place.
-                    from repro.train.faults import truncate_file
-                    truncate_file(path + ".npz")
-                    self._tear_next_ckpt = False
-                self._ring_push(path, step_idx + 1)
-            step_idx += 1
+                if at_ckpt:
+                    path = f"{tcfg.ckpt_path}_{step_idx+1}"
+                    self.save_checkpoint(path, step=step_idx + 1)
+                    self.metrics.event("checkpoint", step=step_idx, path=path)
+                    if self._tear_next_ckpt:
+                        # torn_ckpt fault: simulate a crash mid-write AFTER the
+                        # save completed — the atomic-rename path cannot tear,
+                        # so the injector truncates the archive in place.
+                        from repro.train.faults import truncate_file
+                        truncate_file(path + ".npz")
+                        self._tear_next_ckpt = False
+                    self._ring_push(path, step_idx + 1)
+                step_idx += 1
         self._flush_pending(pending, t0)
         self._global_step = end
         return self.history
@@ -649,42 +653,43 @@ class Trainer:
         """Drain the deferred-metrics buffer: ONE batched device sync, then
         in-order host processing (controller entropy feed, history records,
         telemetry emission) and a registry flush."""
-        if pending:
-            jax.block_until_ready([m["loss"] for (_, _, m, *_rest) in pending])
-        tcfg, ctrl = self.tcfg, self.controller
-        for (s_i, meas, m, b_syn, b_raw, b_full, st_b, ranks, rec_rs,
-             wall) in pending:
-            if meas:
-                self._last_entropy = float(m["entropy"])
-                if "stage_entropy" in m:
-                    self._last_stage_entropy = [
-                        float(h) for h in np.asarray(m["stage_entropy"])]
-                ctrl.on_entropy(s_i, self._last_entropy)
-            if s_i % tcfg.log_every == 0 or s_i == tcfg.total_steps - 1:
-                rec = {
-                    "step": s_i,
-                    "loss": float(m["loss"]),
-                    # zero-order hold: off-gate steps report the most
-                    # recent alpha-gated reading, not the step's 0.0
-                    # placeholder (the sampled trajectory stays usable)
-                    "entropy": self._last_entropy,
-                    "grad_norm": float(m["grad_norm"]),
-                    "lr": float(m["lr"]),
-                    "bytes_synced": b_syn,
-                    "bytes_full": b_full,
-                    "stage_bytes": st_b,
-                    "ranks": ranks,
-                    "wall_s": wall,
-                }
-                if b_raw != b_syn:      # wire coding active
-                    rec["bytes_wire_raw"] = b_raw
-                if rec_rs is not None:
-                    rec["recovery"] = rec_rs
-                self.history.append(rec)
-                self._emit_step_telemetry(s_i, m, b_syn, b_raw, b_full,
-                                          st_b, ranks, wall)
-        pending.clear()
-        self.metrics.flush()
+        with jax.profiler.TraceAnnotation(scopes.FLUSH):
+            if pending:
+                jax.block_until_ready([m["loss"] for (_, _, m, *_rest) in pending])
+            tcfg, ctrl = self.tcfg, self.controller
+            for (s_i, meas, m, b_syn, b_raw, b_full, st_b, ranks, rec_rs,
+                 wall) in pending:
+                if meas:
+                    self._last_entropy = float(m["entropy"])
+                    if "stage_entropy" in m:
+                        self._last_stage_entropy = [
+                            float(h) for h in np.asarray(m["stage_entropy"])]
+                    ctrl.on_entropy(s_i, self._last_entropy)
+                if s_i % tcfg.log_every == 0 or s_i == tcfg.total_steps - 1:
+                    rec = {
+                        "step": s_i,
+                        "loss": float(m["loss"]),
+                        # zero-order hold: off-gate steps report the most
+                        # recent alpha-gated reading, not the step's 0.0
+                        # placeholder (the sampled trajectory stays usable)
+                        "entropy": self._last_entropy,
+                        "grad_norm": float(m["grad_norm"]),
+                        "lr": float(m["lr"]),
+                        "bytes_synced": b_syn,
+                        "bytes_full": b_full,
+                        "stage_bytes": st_b,
+                        "ranks": ranks,
+                        "wall_s": wall,
+                    }
+                    if b_raw != b_syn:      # wire coding active
+                        rec["bytes_wire_raw"] = b_raw
+                    if rec_rs is not None:
+                        rec["recovery"] = rec_rs
+                    self.history.append(rec)
+                    self._emit_step_telemetry(s_i, m, b_syn, b_raw, b_full,
+                                              st_b, ranks, wall)
+            pending.clear()
+            self.metrics.flush()
 
     def _emit_step_telemetry(self, s_i: int, m: dict, b_syn: int,
                              b_raw: int, b_full: int, st_b, ranks,
@@ -811,7 +816,8 @@ class Trainer:
         }
         if self.recovery is not None:
             extra["recovery"] = self.recovery.as_dict()
-        ckpt_mod.save(path, self.state, extra=extra)
+        with jax.profiler.TraceAnnotation(scopes.CHECKPOINT):
+            ckpt_mod.save(path, self.state, extra=extra)
 
     def restore_checkpoint(self, path: str, load_recovery: bool = True) -> int:
         """Restore device tree + control plane; returns the global step.

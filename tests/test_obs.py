@@ -1,6 +1,6 @@
 """Telemetry subsystem: registry semantics, sink round-trips, trainer
-series reconciling with the wire-byte/DAC ledgers, tick-trace span oracle,
-and the fault-event log."""
+series reconciling with the wire-byte/DAC ledgers, and the fault-event
+log."""
 
 import json
 import os
@@ -16,13 +16,9 @@ from repro.data.pipeline import SyntheticLM
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import ModelConfig, build_model
 from repro.obs import (
-    JsonlSink, MemorySink, MetricsRegistry, expected_span_count, load_trace,
-    read_jsonl, tick_trace_events, validate_trace, write_csv,
-    write_chrome_trace,
+    JsonlSink, MemorySink, MetricsRegistry, read_jsonl, write_csv,
 )
-from repro.obs.trace import EXTRA_CATS, SCHEDULED_CATS
 from repro.optim.adam import AdamConfig
-from repro.pipeline.schedule import OverlapPlan, slot_table
 from repro.train.faults import RecoveryConfig, parse_inject
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -177,92 +173,6 @@ def test_trainer_series_reconcile_with_ledgers():
 
     names = {e["name"] for e in sink.events()}
     assert {"run_meta", "plan_change"} <= names
-
-
-# ------------------------------------------------------------ tick traces
-@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
-@pytest.mark.parametrize("S,M", [(2, 4), (4, 8)])
-def test_tick_trace_matches_slot_table_oracle(schedule, S, M):
-    events = tick_trace_events(schedule, S, M, n_units=4)
-    spans = [e for e in events if e["ph"] == "X"]
-    assert all(e["cat"] in SCHEDULED_CATS + EXTRA_CATS for e in spans)
-    scheduled = [e for e in spans if e["cat"] in SCHEDULED_CATS]
-
-    # one span per tick-table entry
-    table = slot_table(schedule, S, M)
-    n_oracle = sum(len(row[t]) for row in table for t in range(len(row)))
-    assert len(scheduled) == n_oracle == expected_span_count(schedule, S, M)
-    assert n_oracle == 2 * S * M     # F and B for every (stage, microbatch)
-
-    # every span matches its table entry's (kind, microbatch) at its tick
-    for e in scheduled:
-        s, t, mb = e["tid"], e["args"]["tick"], e["args"]["microbatch"]
-        kind = "F" if e["cat"] == "forward" else "B"
-        assert (kind, mb) in table[s][t]
-
-    # nesting: scheduled spans on one track never overlap
-    for s in range(S):
-        iv = sorted((e["ts"], e["ts"] + e["dur"])
-                    for e in scheduled if e["tid"] == s)
-        for (a0, a1), (b0, _) in zip(iv, iv[1:]):
-            assert a1 <= b0 + 1e-6
-
-    stats = validate_trace({"traceEvents": events})
-    assert stats["tracks"] == S
-    assert stats["by_cat"].get("bubble", 0) > 0   # filler spans present
-    f_args = next(e["args"] for e in scheduled if e["cat"] == "forward")
-    assert f_args["stash_policy"] == "replay"
-
-    # stash annotations ride on the spans for stashing policies
-    ev_full = tick_trace_events(schedule, S, M, n_units=4,
-                                stash_policy="full")
-    f_full = next(e["args"] for e in ev_full
-                  if e.get("cat") == "forward")
-    assert f_full["stash_points"] == [1, 2, 3]
-    b_full = next(e["args"] for e in ev_full
-                  if e.get("cat") == "backward")
-    assert b_full["replay_segments"]
-
-
-def test_tick_trace_sync_spans_from_overlap_plan():
-    S, M = 2, 4
-    plan = OverlapPlan(schedule="1f1b", num_stages=S, num_microbatches=M,
-                       launches=(((4, (0, 1)),), ((3, (0,)),)),
-                       residual=((2,), ()),
-                       slack_seconds=(0.0, 1.0),
-                       est_sync_seconds=(1.0, 1.0),
-                       feasible=(False, True))
-    events = tick_trace_events("1f1b", S, M, sync_plan=plan)
-    sync = [e for e in events if e.get("cat") == "sync"]
-    resid = [e for e in events if e.get("cat") == "sync-residual"]
-    assert len(sync) == 3 and len(resid) == 1
-    assert expected_span_count("1f1b", S, M, plan) == 2 * S * M + 3
-    assert {e["tid"] for e in sync} == {0, 1}
-    assert resid[0]["tid"] == 0 and resid[0]["args"]["residual"] is True
-    # in-loop chunks start after the stage's last backward
-    last_b = max(e["ts"] + e["dur"] for e in events
-                 if e.get("cat") == "backward" and e["tid"] == 0)
-    assert all(e["ts"] >= last_b - 1e-6 for e in sync if e["tid"] == 0)
-    validate_trace({"traceEvents": events})
-
-
-def test_trace_file_roundtrip_and_validation_errors(tmp_path):
-    events = tick_trace_events("1f1b", 2, 4)
-    path = write_chrome_trace(str(tmp_path / "t" / "trace.json"), events,
-                              metadata={"schedule": "1f1b"})
-    obj = load_trace(path)
-    assert obj["otherData"]["schedule"] == "1f1b"
-    assert validate_trace(obj)["spans"] == len(
-        [e for e in events if e["ph"] == "X"])
-
-    with pytest.raises(ValueError, match="traceEvents"):
-        validate_trace({"events": []})
-    with pytest.raises(ValueError, match="phase"):
-        validate_trace({"traceEvents": [{"ph": "Q", "name": "x"}]})
-    with pytest.raises(ValueError, match="negative"):
-        validate_trace({"traceEvents": [
-            {"ph": "X", "name": "x", "cat": "forward", "ts": 0.0,
-             "dur": -1.0, "pid": 0, "tid": 0}]})
 
 
 # ------------------------------------------------------------- fault log
