@@ -1,7 +1,7 @@
 """Closed-jaxpr traversal + replica-uniformity dataflow for the auditor.
 
 The collective-safety passes (``parity``, ``budget``, ``hostcalls``) all
-need the same three primitives, which live here:
+need the same primitives, which live here:
 
   * :func:`walk` — depth-first traversal of a jaxpr INCLUDING every
     sub-jaxpr reachable through equation params (``cond`` branches,
@@ -9,6 +9,8 @@ need the same three primitives, which live here:
     ``shard_map`` bodies), yielding ``(eqn, path)`` pairs where ``path``
     is a stable, human-readable position string — the path-qualified
     part of every auditor diagnostic.
+  * :func:`walk_trips` — the same traversal, each eqn with how often it
+    runs: the product of the lengths of the scans around it.
   * :func:`collective_signature` — the ORDERED sequence of
     :class:`CollectiveCall` records (primitive, named axes, operand
     shapes/dtypes, comm-relevant params) a jaxpr would issue. Two
@@ -41,6 +43,7 @@ __all__ = [
     "as_jaxpr",
     "subjaxprs",
     "walk",
+    "walk_trips",
     "collective_signature",
     "count_collectives",
     "uniform_env",
@@ -139,6 +142,19 @@ def walk(jaxpr: Any, path: str = "") -> Iterator[tuple[Any, str]]:
         yield eqn, here
         for label, sub in subjaxprs(eqn):
             yield from walk(sub, f"{here}.{label}")
+
+
+def walk_trips(jaxpr: Any, trips: int = 1) -> Iterator[tuple[Any, int]]:
+    """(eqn, trips) over a jaxpr and all nested sub-jaxprs, ``trips`` being
+    how often the eqn runs per run of the jaxpr: the product of the lengths
+    of the scans around it (a ``cond`` branch counts as taken, a ``while``
+    body once)."""
+    for eqn in as_jaxpr(jaxpr).eqns:
+        yield eqn, trips
+        inner = trips * (eqn.params["length"] if eqn.primitive.name == "scan"
+                         else 1)
+        for _, sub in subjaxprs(eqn):
+            yield from walk_trips(sub, inner)
 
 
 def _comm_params(eqn) -> tuple[tuple[str, str], ...]:

@@ -8,7 +8,7 @@ to the ref.py oracles, and handle dtype promotion.
 The train step calls them inside a ``shard_map`` that leaves the ``model``
 axis to GSPMD, and XLA cannot partition a Mosaic call. ``_run``
 makes the axes still Auto at the call site manual for the kernel alone,
-with every operand replicated.
+with every operand replicated (``spmd.manual_over_auto``).
 """
 from __future__ import annotations
 
@@ -16,12 +16,15 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import AxisType, PartitionSpec as P
+
+from repro.analysis.jaxpr_walk import walk_trips
 
 from . import entropy_hist as _hist
+from . import flash_attention_bwd as _flash
 from . import lowrank as _lr
 from . import pack as _pack
 from . import ref
+from .spmd import auto_axes, manual_over_auto, partition_seen
 
 F32 = jnp.float32
 
@@ -36,14 +39,8 @@ def _run(kernel, *args, **static):
     Inside a region with Auto mesh axes the kernel runs whole on each
     device of those axes, its operands replicated.
     """
-    fn = partial(kernel, interpret=_interpret(), **static)
-    mesh = jax.sharding.get_abstract_mesh()
-    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
-            if t == AxisType.Auto}
-    if auto:
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                           axis_names=auto, check_vma=False)
-    return fn(*args)
+    return manual_over_auto(partial(kernel, interpret=_interpret(),
+                                    **static))(*args)
 
 
 def _tileable(m: int, n: int) -> bool:
@@ -119,6 +116,52 @@ def orthonormalize3(p):
     if m * r * 4 > (4 << 20) or m % 8 != 0:
         return jax.vmap(lambda x: jnp.linalg.qr(x.astype(F32))[0])(p)
     return _run(_lr.gram_schmidt_panel_batched, p)
+
+
+# ------------------------------------------------ causal flash attention
+# The model's training attention (models/layers.py::attn_apply). Interpreted,
+# the kernels are far slower than XLA's blockwise attention, so the route
+# takes them only on a compiled backend.
+
+# q and k tile, cut to T where T is shorter: at T = 1024, Dh = 64 on v5e one
+# whole-sequence tile beats 512 (fewer grid steps) and 256 or 128 by far
+# (PERF.md §6).
+FLASH_BLOCK = 1024
+
+
+def _flash_backend() -> bool:
+    return not _interpret()
+
+
+def flash_attention_fits(B: int, T: int, kv_heads: int, head_dim: int, *,
+                         causal: bool, window: int) -> bool:
+    """Whether causal self-attention of B sequences of T tokens takes the
+    flash kernels: on a compiled backend, without a window, at a shape they
+    tile, where the partition is seen (``spmd.partition_seen``) and the
+    mesh axes still Auto split the batch and the heads evenly
+    (``flash_attention_bwd.partitions``)."""
+    block = min(FLASH_BLOCK, T)
+    return (_flash_backend() and causal and window == 0 and T % block == 0
+            and block % 128 == 0 and head_dim % 16 == 0 and partition_seen()
+            and _flash.partitions(B, kv_heads, auto_axes()))
+
+
+def flash_attention_calls(jaxpr) -> int:
+    """Flash attention calls a traced step makes per run: launches of the
+    dK/dV kernel, one in each call's backward, every trip of an enclosing
+    scan counted (``jaxpr_walk.walk_trips``)."""
+    return sum(trips for eqn, trips in walk_trips(jaxpr)
+               if eqn.primitive.name == "pallas_call"
+               and eqn.params["jaxpr"].debug_info.func_name
+               == _flash.DKV_KERNEL)
+
+
+def flash_attention(q, k, v):
+    """Causal GQA self-attention (B, T, H, Dh) through the Pallas flash
+    forward and backward kernels (a custom VJP that saves O and the LSE)."""
+    block = min(FLASH_BLOCK, q.shape[1])
+    return _flash.flash_attention_train(q, k, v, True, block, block,
+                                        _interpret())
 
 
 # legacy alias used by core.powersgd's use_kernels path

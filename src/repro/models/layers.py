@@ -10,7 +10,8 @@ Conventions used across the zoo:
   * Attention is grouped-query (GQA) with optional qk-norm, qkv-bias,
     sliding window, RoPE or learned/sinusoidal positions; the prefill path
     is blockwise (online softmax) so 32k-token prefill never materializes a
-    full (T x T) score matrix.
+    full (T x T) score matrix. Causal self-attention that the Pallas flash
+    kernels tile runs through them on a compiled backend (``attn_apply``).
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import ops
 
 Params = dict[str, Any]
 F32 = jnp.float32
@@ -213,13 +216,24 @@ def attn_apply(
     use_rope: bool = True, window: int = 0, norm_eps: float = 1e-5,
     block_q: int = 512,
 ):
-    """Full-sequence (training / prefill) GQA attention."""
+    """Full-sequence (training / prefill) GQA attention.
+
+    Causal self-attention without a window goes through the Pallas flash
+    kernels where ``ops.flash_attention_fits`` (a compiled backend, a shape
+    they tile, heads and batch split evenly over the Auto mesh axes);
+    everything else through ``blockwise_attention``.
+    """
     B, T, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     q, k, v = _project_qkv(p, x, num_heads, num_kv_heads, head_dim,
                            positions, rope_theta, use_rope, norm_eps)
-    o = blockwise_attention(q, k, v, causal=causal, window=window, block_q=block_q)
+    if ops.flash_attention_fits(B, T, num_kv_heads, head_dim, causal=causal,
+                                window=window):
+        o = ops.flash_attention(q, k, v)
+    else:
+        o = blockwise_attention(q, k, v, causal=causal, window=window,
+                                block_q=block_q)
     o = o.reshape(B, T, num_heads * head_dim)
     out = jnp.einsum("bte,ed->btd", o, p["wo"], preferred_element_type=F32)
     return out.astype(x.dtype)

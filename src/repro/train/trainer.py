@@ -34,6 +34,7 @@ from repro.core import wire
 from repro.core.bucketing import bucketing_supported, make_bucket_layout
 from repro.core.config import SYNC_FIELDS, SyncConfig, alias_property, \
     resolve_embedded
+from repro.kernels import ops
 from repro.models.model import Model
 from repro.obs import scopes
 from repro.optim import adam
@@ -232,6 +233,7 @@ class Trainer:
                 [t * t_mb for t in self.overlap_plan.slack_seconds])
 
         self._step_cache: dict[Any, Any] = {}
+        self._routes_logged: set = set()
         self.step_configs: dict[Any, TrainStepConfig] = {}
         self.history: list[dict] = []
         self.bytes_synced = 0           # exact DP wire bytes so far (coded)
@@ -373,6 +375,17 @@ class Trainer:
                 donate_argnums=0,
             )
         return self._step_cache[key]
+
+    def _log_attention_route(self, step_fn, batch, measure_entropy: bool):
+        """Log, once per step variant, how many attention calls per step go
+        through the flash kernels (``attention_route``), read from the
+        variant's traced jaxpr; its first call re-uses this trace."""
+        if step_fn in self._routes_logged:
+            return
+        self._routes_logged.add(step_fn)
+        jaxpr = step_fn.trace(self.state, batch).jaxpr
+        self.metrics.event("attention_route", measure_entropy=measure_entropy,
+                           flash=ops.flash_attention_calls(jaxpr))
 
     def step_cache_keys(self) -> tuple:
         """Every ``(plan, measure_entropy, sync_cfg)`` key a compiled step
@@ -523,6 +536,7 @@ class Trainer:
                 # device work (§IV-B's "fraction of iterations" sampling).
                 measure = tcfg.measure_entropy and ctrl.wants_entropy(step_idx)
                 step_fn = self._get_step(measure)
+                self._log_attention_route(step_fn, batch, measure)
                 self.state, mets = step_fn(self.state, batch)
 
                 self.bytes_synced += comp_bytes

@@ -251,16 +251,18 @@ def test_flash_matches_model_blockwise():
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("shape", [(2, 128, 4, 2, 32), (1, 256, 8, 8, 64)])
-def test_flash_backward_matches_autodiff(causal, shape):
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 128), (128, 64)])
+def test_flash_backward_matches_autodiff(causal, shape, blocks):
     """custom_vjp flash bwd vs jax.grad of the full-materialization oracle."""
     from repro.kernels.flash_attention_bwd import flash_attention_train
     B, T, H, Hkv, D = shape
+    bq, bk = blocks
     q = _rand((B, T, H, D), jnp.float32, 41)
     k = _rand((B, T, Hkv, D), jnp.float32, 42)
     v = _rand((B, T, Hkv, D), jnp.float32, 43)
 
     def loss_flash(q, k, v):
-        return jnp.sum(jnp.sin(flash_attention_train(q, k, v, causal, 64, 64)))
+        return jnp.sum(jnp.sin(flash_attention_train(q, k, v, causal, bq, bk)))
 
     def loss_ref(q, k, v):
         return jnp.sum(jnp.sin(ref.flash_reference(q, k, v, causal=causal)))

@@ -1,22 +1,30 @@
-"""Compile the compressor's Pallas kernels for a described TPU v5e.
+"""Compile the Pallas kernels of the train step for a described TPU v5e.
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for
 one chip of a ``v5e:2x2`` topology that is described, not attached, at the
 shapes GPT-2 345M compresses (d_model 1024, d_ff 4096, fused QKV 3072) and
-rank 32. A kernel that only works in interpret mode fails here. The
-topology is described inside a fixture, so only the worker that runs this
-file loads the TPU library, and the tests skip where it cannot be described.
+rank 32, and the flash attention forward and backward at its attention
+(16 x 1024 tokens, 16 heads of 64), on one chip and split over a
+data=2 x model=2 mesh of the four. A kernel that only works in interpret
+mode fails here. The topology is described inside a fixture, so only the
+worker that runs this file loads the TPU library, and the tests skip where
+it cannot be described.
 """
 import functools
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.kernels import lowrank as lr
-from repro.kernels import pack
+from repro.kernels import ops, pack
+from repro.kernels.flash_attention_bwd import flash_attention_train
+from repro.dist.collectives import shard_map_dp
+from repro.models import layers as L
 
 RANK = 32
 E = 24                      # one stacked shape group: every layer of 345M
@@ -25,7 +33,7 @@ PAYLOAD_CODES = 1024 * 3072     # one full-size leaf, coded
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -39,8 +47,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compiled_text(fn, sharding, *shapes):
@@ -96,3 +109,52 @@ def test_pack_kernels_compile_for_v5e(one_chip, bits):
         one_chip, ((nwords,), jnp.uint32))
     assert "tpu_custom_call" in packed
     assert "tpu_custom_call" in unpacked
+
+
+@pytest.mark.parametrize("kv_heads", [16, 4], ids=["mha", "gqa"])
+def test_flash_attention_train_compiles_for_v5e(one_chip, kv_heads):
+    B, T, H, Dh = 16, 1024, 16, 64
+    block = min(ops.FLASH_BLOCK, T)
+
+    def loss(q, k, v):
+        o = flash_attention_train(q, k, v, True, block, block, False)
+        return jnp.sum(o.astype(F32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                          ((B, T, H, Dh), BF16), ((B, T, kv_heads, Dh), BF16),
+                          ((B, T, kv_heads, Dh), BF16))
+    assert text.count("tpu_custom_call") >= 3       # fwd, dq, dkv
+
+
+def test_flash_attention_keeps_heads_split_under_tp(v5e, monkeypatch):
+    """The train step's layout on data=2 x model=2: data manual, model Auto,
+    wq/wk/wv column- and wo row-parallel. The flash kernels run on each
+    device's own 8 heads of its 8 sequences: the compiled gradient gathers
+    nothing, and only reduces (Megatron's all-reduces of x's and wo's
+    partial sums)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
+    B, T, H, Dh = 16, 1024, 16, 64
+    d = H * Dh
+    cols, rows = P(None, "model"), P("model", None)
+    specs = {"wq": cols, "wk": cols, "wv": cols, "wo": rows}
+    p = {n: jax.ShapeDtypeStruct((d, d), BF16,
+                                 sharding=NamedSharding(mesh, s))
+         for n, s in specs.items()}
+    x = jax.ShapeDtypeStruct((B, T, d), BF16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def local(p, x):
+        def loss(p):
+            o = L.attn_apply(p, x, num_heads=H, num_kv_heads=H, head_dim=Dh)
+            return jnp.sum(o.astype(F32))
+        return jax.tree_util.tree_map(lambda g: jax.lax.pmean(g, "data"),
+                                      jax.grad(loss)(p))
+
+    step = shard_map_dp(local, mesh, in_specs=(P(), P("data")),
+                        out_specs=P(), manual_axes=("data",))
+    text = jax.jit(step).lower(p, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # fwd, dq, dkv
+    assert "bf16[64,1024,64]" in text          # 8 sequences x 8 heads each
+    assert "all-gather" not in text
