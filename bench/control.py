@@ -35,7 +35,7 @@ def upper_readings(model: dict, wl: dict, seed: int) -> dict:
     """The control's and the half-batch fault's gaps to the reference."""
     import check
     import harness
-    import reference
+    reference = harness.reference_for(model)
     batches = harness.make_ring(model, wl, seed)[:harness.CHECKED_STEPS]
     seed32 = harness.program_seed(seed)
     ref = reference.run(model, wl, seed32, batches)

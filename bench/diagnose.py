@@ -30,8 +30,8 @@ sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
 def flat(tree) -> dict:
     import jax
     import numpy as np
-    import reference
-    return {reference.path_of(kp): np.array(v, np.float32) for kp, v in
+    from reference import path_of
+    return {path_of(kp): np.array(v, np.float32) for kp, v in
             jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
@@ -51,7 +51,8 @@ def compare(model: dict, wl: dict, seed: int, devices) -> dict:
     import jax.numpy as jnp
     import numpy as np
     import harness
-    import reference
+    from reference import path_of
+    reference = harness.reference_for(model)
 
     host = harness.make_ring(model, wl, seed)
     ring = [jax.device_put(b, devices[0]) for b in host]
@@ -96,7 +97,7 @@ def compare(model: dict, wl: dict, seed: int, devices) -> dict:
     for k in (1, 2):
         for who, src in (("program", P), ("reference", R)):
             p = jax.tree_util.tree_unflatten(tdef, [
-                jnp.asarray(src[k][reference.path_of(kp)])
+                jnp.asarray(src[k][path_of(kp)])
                 for kp, _ in leaves])
             cross[f"{who}_after_{k}"] = float(f32_loss(
                 p, jnp.asarray(host[k]["tokens"]),

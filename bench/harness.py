@@ -4,7 +4,8 @@ Everything that belongs to one cell is read from files found by name:
 ``bench/workloads/<cell>.json`` (model configuration, chips, policy, rank,
 GDS/DAC, batch, optimizer, limits) and ``bench/configs/<config>.json`` (the
 model as it is run). The metrics are the readers ``bench/metrics/<name>.py``
-that ``BENCHMARK.json`` lists for the cell.
+that ``BENCHMARK.json`` lists for the cell; the plain reference is the
+module of the configuration's family (``reference_for``).
 
 The window drives the program's normal path, ``repro.train.trainer.Trainer``
 built as ``repro.launch.train`` builds one. Set-up builds that one trainer,
@@ -12,10 +13,22 @@ drives it from the seed through its first steps (which compiles both step
 variants the window uses, entropy on and off), and hands the same object to
 the window. The first three steps are compared with the reference after
 the window has closed and the program's state is freed.
+
+A metric reader's ``read(ctx)`` gets one context (``run_cell``):
+``setup_s``, ``window_s``, ``steps``, ``tokens``, ``chips``,
+``flops_per_token``, ``device_kind``, ``window_compiles``, ``workload`` and
+``model`` (the cell's files), ``events`` (every record the trainer's
+``MetricsRegistry`` emitted in set-up and the window, by name: events,
+counters, scalars, series, as ``repro.obs.MemorySink`` keeps them) and,
+with ``--trace 1``, ``reduced`` (``reduce_trace``: busy and idle time, step
+modules and the window by ``edgc.*`` scope, op and Pallas kernel, and host
+span, ``xplane.reduce_window`` and ``xplane.reduce_scopes``); ``None``
+without a trace.
 """
 from __future__ import annotations
 
 import gc
+import importlib
 import importlib.util
 import json
 import shutil
@@ -58,6 +71,33 @@ def read_metric(name: str, ctx) -> float | None:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read(ctx)
+
+
+def reference_for(model: dict):
+    """The plain reference of ``model``'s family: ``reference.py`` for
+    ``dense``, else ``reference_<family>.py`` beside it, as
+    ``flops.flops_per_token`` finds ``flops_<family>.py``.
+
+    A family module gives:
+
+    * ``init_params(model, key)``: the initial weights that the program
+      derives from ``key``, as a pytree of the program's parameter paths;
+    * ``run(model, wl, seed, batches, steps=3, precision="f32", rows=4,
+      update=True)``: the first ``steps`` steps on ``batches``, a dict with
+      ``loss`` (one per step), ``entropy`` (step 0's, or ``None``),
+      ``grad`` (each leaf's norm of the first gradient as the optimizer
+      gets it) and ``change`` (each leaf's norm of the change after
+      ``steps``), leaves named by ``reference.path_of`` and normed by
+      ``reference.leaf_norms``. ``precision="fp8"`` is the control (model
+      matrix products' operands in scaled float8); ``rows`` the rows per
+      block of the gradient; ``update=False`` skips every update;
+    * ``COMPRESSED``: the names of the leaves it compresses.
+
+    ``bench/diagnose.py`` also takes ``init_state``, ``make_step``,
+    ``loss_fn`` and ``MATMUL`` as ``reference.py`` has them."""
+    family = model.get("family", "dense")
+    return importlib.import_module(
+        "reference" if family == "dense" else f"reference_{family}")
 
 
 class CompileClock:
@@ -138,7 +178,8 @@ def change_norms(params_host, model: dict, seed: int) -> dict[str, float]:
     import jax
     import jax.numpy as jnp
     import reference
-    p0 = reference.init_params(model, jax.random.PRNGKey(program_seed(seed)))
+    p0 = reference_for(model).init_params(
+        model, jax.random.PRNGKey(program_seed(seed)))
     delta = jax.tree_util.tree_map(
         lambda p, q: jnp.asarray(p).astype(jnp.float32) - q, params_host, p0)
     del p0
@@ -153,19 +194,24 @@ def free(tree) -> None:
 
 
 def run_cell(name: str, wl: dict, cfg: dict, seed: int, seconds: float,
-             trace: bool, devices, t_start: float) -> dict:
-    """Set up, time the window, check; return the result line's fields."""
+             trace: bool, devices, t_start: float, observe=None) -> dict:
+    """Set up, time the window, check; return the result line's fields.
+    ``observe``, where given, is called with the metric context once the
+    metrics are read."""
     import jax
     import numpy as np
     import check
     import flops
     import reference
+    from repro.obs import MemorySink
 
     model = cfg["model"]
     clock = CompileClock()
     host_ring = make_ring(model, wl, seed)
     ring = [jax.device_put(b, devices[0]) for b in host_ring]
     tr = build_trainer(model, wl, seed, devices)
+    records = MemorySink()      # host-side list appends at the flushes
+    tr.metrics.sinks.append(records)
     data = feed(ring)
     b1 = wl["adam"].get("betas", (0.9, 0.95))[0]
 
@@ -233,23 +279,29 @@ def run_cell(name: str, wl: dict, cfg: dict, seed: int, seconds: float,
     t_check = time.perf_counter()
     prog["change"] = change_norms(params_host, model, seed)
     del params_host
-    ref = reference.run(model, wl, program_seed(seed),
-                        host_ring[:CHECKED_STEPS], CHECKED_STEPS)
+    ref = reference_for(model).run(model, wl, program_seed(seed),
+                                   host_ring[:CHECKED_STEPS], CHECKED_STEPS)
     values = check.readings(prog, ref)
     correct, table = check.judge(values, wl["limits"])
 
     dev = used[0]
+    events: dict[str, list[dict]] = {}
+    for rec in records.records:
+        events.setdefault(rec["name"], []).append(rec)
     ctx = types.SimpleNamespace(
         setup_s=setup_s, window_s=window_s, steps=steps,
         tokens=steps * wl["batch"] * wl["seq_len"], chips=len(used),
         flops_per_token=flops.flops_per_token(model, wl["seq_len"]),
         device_kind=dev.device_kind, reduced=reduced,
-        window_compiles=window_compiles, workload=wl, model=model)
+        window_compiles=window_compiles, workload=wl, model=model,
+        events=events)
     metrics = {}
     for m in cell_metrics(name, trace):
         value = read_metric(m["name"], ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    if observe is not None:
+        observe(ctx)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(used), "memory_peak_bytes": mem_peak}
     result = {"correct": correct, "attempted": steps, "failed": failed,
@@ -257,8 +309,10 @@ def run_cell(name: str, wl: dict, cfg: dict, seed: int, seconds: float,
     if reduced is not None:
         device["busy_s"] = (reduced["busy_ns"] or 0) / 1e9
         device["window_s"] = reduced["window_ns"] / 1e9
+        ops = sorted(((n, row["ns"]) for n, row in reduced["ops"].items()),
+                     key=lambda kv: -kv[1])[:10]
         result["breakdown"] = {
-            "device_ops": [[n, t / 1e9] for n, t in reduced["device_ops"]],
+            "device_ops": [[n, t / 1e9] for n, t in ops],
             "idle_gaps": [[n, t / 1e9] for n, t in reduced["idle_gaps"]]}
     result["check"] = table
     print(f"[bench] {name} seed {seed}: setup_s {setup_s:.3f}, {steps} steps "
@@ -277,11 +331,20 @@ def run_cell(name: str, wl: dict, cfg: dict, seed: int, seconds: float,
 
 
 def reduce_trace(log_dir: str) -> dict:
-    """The window of the trace in ``log_dir``, reduced."""
+    """The window of the trace in ``log_dir``, reduced: busy and idle time,
+    step modules and breakdown (``xplane.reduce_window``), and the window
+    by scope, op, kernel and host span (``xplane.reduce_scopes``)."""
     import xplane
-    tr = xplane.load(xplane.find_xplane(log_dir))
+    path = xplane.find_xplane(log_dir)
+    return reduce_loaded(xplane.load(path), xplane.hlo_instructions(path))
+
+
+def reduce_loaded(tr, instrs: dict) -> dict:
+    """``reduce_trace`` of a loaded trace and its programs' instructions."""
+    import xplane
     found = xplane.span(tr, WINDOW_SPAN)
     if found is None:
         raise RuntimeError(f"the trace holds no {WINDOW_SPAN} span")
     line, lo, hi = found
-    return xplane.reduce_window(tr, lo, hi, line)
+    return {**xplane.reduce_window(tr, lo, hi, line),
+            **xplane.reduce_scopes(tr, instrs, lo, hi)}

@@ -35,15 +35,20 @@ def fail(msg: str) -> int:
 
 def setup_cache(jax) -> None:
     """Persistent compilation cache at a fixed path in the checkout (or
-    where ``JAX_COMPILATION_CACHE_DIR`` says), every compile kept."""
+    where ``JAX_COMPILATION_CACHE_DIR`` says), every compile kept. The key
+    includes the programs' metadata, so that a cached program carries the
+    ``op_name`` scopes of the code that runs, which the trace's scope
+    readings are taken from."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           str(ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
-def main(argv=None) -> int:
+def main(argv=None, observe=None) -> int:
+    """Run the cell; ``observe`` as ``harness.run_cell`` takes it."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -70,7 +75,8 @@ def main(argv=None) -> int:
                     f"found {len(devices)}")
 
     result = harness.run_cell(args.workload, wl, cfg, args.seed, args.seconds,
-                              bool(args.trace), devices, T_START)
+                              bool(args.trace), devices, T_START,
+                              observe=observe)
     for name, row in result["check"].items():
         print(f"check {name} {row['value']!r} limit {row['limit']!r}",
               file=sys.stderr)
